@@ -166,6 +166,9 @@ def run(*, smoke: bool = False, json_path: str = ""):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small matrix + acceptance assertions (CI)")

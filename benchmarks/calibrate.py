@@ -169,6 +169,9 @@ def run(*, json_path: str = "", smoke: bool = False, bench_json: str = ""):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="fewer iterations / smaller scan sizes (CI)")

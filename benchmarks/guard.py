@@ -32,7 +32,7 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import GuardConfig, SpTRSV
 from repro.core.sweep import default_residual_tol
 from repro.sparse import inject_values, lung2_like
@@ -163,6 +163,9 @@ def run(*, smoke: bool = False, json_path: str = ""):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small matrix + acceptance assertions (CI)")

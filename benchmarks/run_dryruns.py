@@ -41,6 +41,7 @@ def run_one(arch, shape, mesh, timeout=7200):
     path = cell_path(arch, shape, mesh)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"  # children never take the chip
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--mesh", mesh, "--out", OUT]
     t0 = time.time()

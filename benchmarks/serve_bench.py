@@ -30,7 +30,7 @@ import time
 
 import numpy as np
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import CSRMatrix, SpTRSV
 from repro.serve import SolverRegistry, SolveService
 from repro.sparse import lung2_like, refresh_values, serve_traffic
@@ -211,6 +211,9 @@ def run(*, smoke: bool = False, json_path: str = ""):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small matrices + acceptance assertions (CI)")

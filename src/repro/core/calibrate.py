@@ -17,9 +17,9 @@ interpret-mode backends execute on the host and are priced as ``cpu``).
 ``cpu``   the historical planner constants (the interpreter / XLA:CPU path
           the test-suite runs) — ``fused_max_rows=0`` because pallas has no
           compiled CPU lowering, so the fused kernel is never a candidate
-``tpu``   one sequential-grid dispatch for the fused solve
-          (``fused_num_launches="one"``), VMEM-bounded at ~2M f32 rows,
-          128-wide lanes
+``tpu``   128-wide lanes; ``fused_max_rows=0`` because the v5e Mosaic
+          compiler refuses the fused kernel's gather (its dispatch shape
+          stays ``fused_num_launches="one"``: one sequential grid)
 ``gpu``   kernel launches are the synchronization primitive (pricier than a
           TPU grid step), the fused layout executes as one launch **per
           wavefront span** (``fused_num_launches="per_level"``), 32-wide
@@ -111,19 +111,17 @@ class BackendCalibration:
             self.fused_num_launches
 
 
-# f32 VMEM budget for the TPU fused kernel's resident x (~16 MiB, leave half
-# for slab blocks).
-_TPU_FUSED_VMEM_ROWS = 2_000_000
-
 DEFAULT_CALIBRATIONS: Dict[str, BackendCalibration] = {
     # Historical planner constants — the host path every CI run exercises.
     "cpu": BackendCalibration(backend="cpu"),
-    # One sequential-grid dispatch covers the whole fused solve; x resident
-    # in VMEM bounds n.
+    # The fused kernel is no candidate: Mosaic refuses its gather (jnp.take
+    # over the whole VMEM-resident x; only a gather whose indices have the
+    # operand's own 2-D shape lowers), so the v5e compiler rejects it at the
+    # first solve.  A gather kernel that compiles sets this again.
     "tpu": BackendCalibration(
         backend="tpu",
         lane_width=128,
-        fused_max_rows=_TPU_FUSED_VMEM_ROWS,
+        fused_max_rows=0,
         fused_num_launches="one",
         gemm_cost=0.05,   # MXU: dense block flops are nearly free
         trsm_cost=32.0,

@@ -30,7 +30,9 @@ Strategy planner
 pallas_fused for ``SpTRSV.build(..., strategy="auto")`` from the
 :class:`~repro.core.analysis.MatrixAnalysis` and schedule cost — chains go to
 the ``lax.scan`` serial solver, level-parallel matrices to the (coarsened)
-level-set executors, VMEM-sized matrices on a real TPU to the fused kernel.
+level-set executors, and to the fused kernel only where the calibration
+row says a compiled fused dispatch exists (the GPU family today; the v5e
+compiler refuses the TPU fused kernel).
 
 Pricing is **backend-aware**: the model's coefficients (launch cost, gather
 throughput, lane width, serial-scan cost, whether/how a fused single-dispatch
@@ -40,9 +42,10 @@ solve exists) come from the per-device calibration table in
 platform checks in the planner.  The keys that differ across families:
 ``launch_cost`` (a GPU kernel launch is the barrier and is pricier than a
 TPU grid step), ``gather_cost`` (relative padded-FLOP price),
-``fused_max_rows`` (0 on cpu — pallas has no compiled CPU lowering;
-VMEM-bounded on TPU; GMEM-bounded on GPU) and ``fused_num_launches``
-(``"one"`` TPU sequential grid vs ``"per_level"`` GPU span walk).
+``fused_max_rows`` (0 on cpu — pallas has no compiled CPU lowering; 0 on
+TPU — Mosaic refuses the fused kernel's gather; GMEM-bounded on GPU) and
+``fused_num_launches`` (``"one"`` TPU sequential grid vs ``"per_level"``
+GPU span walk).
 """
 from __future__ import annotations
 

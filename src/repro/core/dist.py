@@ -43,8 +43,8 @@ from typing import Callable, List
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from .codegen import GATHER_UNROLL_MAX_K, Schedule, _gather_sum, stack_sub_slabs
 from .packed import PackedLayout, build_packed_layout, pack_values
@@ -295,29 +295,35 @@ def make_packed_distributed_solver(
     def _seg_arrays(vals_flat, diag_flat):
         return (_seg_slices(vals_flat, "val"), _seg_slices(diag_flat, "diag"))
 
-    vals_h, diag_h = _seg_arrays(layout.vals_flat, layout.diag_flat)
-    cols_d = tuple(jnp.asarray(c)
-                   for c in _seg_slices(layout.cols_flat, "col"))
-    values0 = (tuple(jnp.asarray(v) for v in vals_h),
-               tuple(jnp.asarray(d) for d in diag_h))
-    perm_d = jnp.asarray(layout.perm)
-    pos_d = jnp.asarray(layout.pos)
-
-    def repack(target_data: np.ndarray):
-        vf, df = pack_values(layout, target_data)
-        vs, ds = _seg_arrays(vf, df)
-        return (tuple(jnp.asarray(v) for v in vs),
-                tuple(jnp.asarray(d) for d in ds))
-
     rep = [s.kind == "chain" for s in segs]
+    slab_specs = tuple(P() if r else P(None, axis) for r in rep)
+    diag_specs = tuple(P() if r else P(axis) for r in rep)
     in_specs = (
         P(),                                              # b (replicated)
         P(),                                              # perm
         P(),                                              # pos
-        tuple(P() if r else P(None, axis) for r in rep),  # vals
-        tuple(P() if r else P(axis) for r in rep),        # diag
-        tuple(P() if r else P(None, axis) for r in rep),  # cols (positions)
+        slab_specs,                                       # vals
+        diag_specs,                                       # diag
+        slab_specs,                                       # cols (positions)
     )
+
+    def _place(arrays, specs):
+        # buffers live where the shard_map reads them: each device holds
+        # its shard (replicated chains: a copy), never only devices[0]
+        return tuple(jax.device_put(a, NamedSharding(mesh, sp))
+                     for a, sp in zip(arrays, specs))
+
+    def _place_values(vals_flat, diag_flat):
+        vs, ds = _seg_arrays(vals_flat, diag_flat)
+        return _place(vs, slab_specs), _place(ds, diag_specs)
+
+    values0 = _place_values(layout.vals_flat, layout.diag_flat)
+    cols_d = _place(_seg_slices(layout.cols_flat, "col"), slab_specs)
+    perm_d = jnp.asarray(layout.perm)
+    pos_d = jnp.asarray(layout.pos)
+
+    def repack(target_data: np.ndarray):
+        return _place_values(*pack_values(layout, target_data))
 
     def _solve(b, perm, pos, vals_t, diag_t, cols_t):
         dt = b.dtype

@@ -56,7 +56,7 @@ def make_compressed_grad_fn(mesh, axis: str = "pod"):
     """Tree-level wrapper: all-reduce grads over ``axis`` with EF-int8.
     Used when the training step keeps grads sharded per-pod and performs the
     cross-pod reduction explicitly (shard_map region)."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def reduce_tree(grads, state: CompressionState):

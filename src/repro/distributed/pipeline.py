@@ -19,7 +19,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["gpipe_stage_fn", "make_gpipe"]

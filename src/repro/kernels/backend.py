@@ -23,8 +23,12 @@ Backend specs (strings accepted anywhere a ``backend=`` knob appears):
                     compiled TPU lowerings, ``gpu``/``cuda``/``rocm`` → the
                     compiled GPU lowerings, ``cpu`` → the interpret backend
                     (pallas has no CPU codegen; interpret is the only way a
-                    pallas strategy can execute there)
-``"tpu"``           compiled Mosaic lowerings
+                    pallas strategy can execute there); any other platform
+                    raises
+``"tpu"``           compiled Mosaic lowerings.  Only ``trsm_block``
+                    compiles for v5e; the gather kernels (``sptrsv_level``,
+                    ``sptrsv_fused``, ``spmv_ell``) are refused at build
+                    by :func:`refuse_mosaic_gather`
 ``"gpu"``           compiled pallas-triton lowerings (aliases: ``cuda``,
                     ``rocm``)
 ``"interpret"``     TPU lowerings under the pallas interpreter (the
@@ -49,6 +53,7 @@ __all__ = [
     "BACKENDS",
     "resolve_backend",
     "default_backend_name",
+    "refuse_mosaic_gather",
     "warn_interpret_deprecated",
 ]
 
@@ -124,8 +129,29 @@ def default_backend_name() -> str:
         return "tpu"
     if platform in ("gpu", "cuda", "rocm"):
         return "gpu"
-    # cpu (and anything unknown): pallas kernels can only run interpreted
-    return "interpret"
+    if platform == "cpu":
+        # pallas has no CPU codegen: kernels can only run interpreted
+        return "interpret"
+    raise ValueError(
+        f"no kernel backend for JAX platform {platform!r}; pass backend= "
+        f"explicitly (one of {sorted(set(BACKENDS))})")
+
+
+def refuse_mosaic_gather(bk: KernelBackend, kernel: str) -> None:
+    """Refuse, at build time, a compiled ``tpu`` backend for a kernel that
+    gathers rows of a whole VMEM-resident vector (``jnp.take`` with
+    indices of another shape than the operand).  Mosaic lowers a gather
+    only when the indices have the operand's own 2-D shape, so the chip's
+    compiler refuses these kernels at their first solve; raising here
+    names that refusal before any work is queued.  Interpret backends and
+    the GPU family are unaffected."""
+    if bk.platform == "tpu" and not bk.interpret:
+        raise NotImplementedError(
+            f"{kernel}: the Mosaic compiler refuses this kernel's gather on "
+            "a TPU (NotImplementedError: Only 2D gather is supported; the "
+            "batched fused kernel: ValueError: Shape mismatch in input, "
+            "indices and output); use strategy='levelset' or 'auto', or "
+            "backend='interpret' for a correctness run")
 
 
 def resolve_backend(

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 __all__ = ["flash_fwd"]
 
@@ -98,7 +97,7 @@ def flash_fwd(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
@@ -124,7 +123,7 @@ def flash_fwd(
             pltpu.VMEM((block_q,), jnp.float32),      # m
             pltpu.VMEM((block_q,), jnp.float32),      # l
         ],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY),
         ),

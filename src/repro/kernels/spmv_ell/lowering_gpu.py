@@ -1,7 +1,7 @@
 """GPU (pallas-triton) lowering: ELL SpMV  y = M v.
 
 Twin of :mod:`.lowering_tpu` with the Mosaic-isms removed: gather loads
-(``pl.load`` with an index array) from the GMEM-resident input vector
+(ref indexing with an index array) from the GMEM-resident input vector
 replace ``jnp.take`` over a VMEM-resident copy, the grid is an ordinary
 parallel launch (SpMV has no cross-block dependence), and there are no
 TPU compiler params.  Same signature, layout, and padding contract.
@@ -21,7 +21,7 @@ def spmv_kernel(v_ref, cols_ref, vals_ref, out_ref):
     K, C = cols_ref.shape
     acc = jnp.zeros((C,), out_ref.dtype)
     for k in range(K):
-        acc = acc + vals_ref[k, :] * pl.load(v_ref, (cols_ref[k, :],))
+        acc = acc + vals_ref[k, :] * v_ref[cols_ref[k, :]]
     out_ref[...] = acc
 
 
@@ -32,7 +32,7 @@ def spmv(
     vals: jnp.ndarray,    # (K, n_pad)
     *,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     K, n_pad = cols.shape
     assert n_pad % block == 0, (n_pad, block)

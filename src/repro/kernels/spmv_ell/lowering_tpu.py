@@ -6,6 +6,11 @@ fully parallel pass, and (b) matvecs in the iterative-solver examples.
 Grid walks column blocks of the slab (rows of y); ``v`` is VMEM-resident in
 full.  Memory-bound: bytes = (2*K*n)*4 slab + n*4 in/out; the K loop is
 unrolled (K static — matrix-specialized program).
+
+The v5e Mosaic compiler refuses the ``jnp.take`` over the whole ``v``
+(``NotImplementedError: Only 2D gather is supported``); a compiled ``tpu``
+backend is refused at build
+(:func:`repro.kernels.backend.refuse_mosaic_gather`).
 """
 from __future__ import annotations
 
@@ -16,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 __all__ = ["spmv_kernel", "spmv"]
 
@@ -37,7 +41,7 @@ def spmv(
     vals: jnp.ndarray,    # (K, n_pad)
     *,
     block: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     K, n_pad = cols.shape
     assert n_pad % block == 0, (n_pad, block)
@@ -52,7 +56,7 @@ def spmv(
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), v_pad.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,),
         ),
         interpret=interpret,

@@ -9,7 +9,7 @@ import numpy as np
 
 from repro.core.codegen import build_ell
 from repro.core.csr import CSRMatrix
-from repro.kernels.backend import resolve_backend
+from repro.kernels.backend import refuse_mosaic_gather, resolve_backend
 
 from . import lowering_gpu, lowering_tpu
 
@@ -35,6 +35,7 @@ def make_spmv(
     block: int = 1024,
 ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     bk = resolve_backend(backend, interpret=interpret)
+    refuse_mosaic_gather(bk, "spmv_ell")
     low = select_lowering(bk)
     ell = build_ell(M)
     n = M.n

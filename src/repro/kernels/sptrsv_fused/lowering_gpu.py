@@ -45,7 +45,7 @@ def fused_solve(
     *,
     chunk: int = 512,
     spans: tuple = (),      # ((off, r_pad), ...) chunk-aligned wavefronts
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Level-scheduled walk of the fused layout; one launch per wavefront."""
     K, n_pad = cols.shape
@@ -73,7 +73,7 @@ def fused_solve_batched(
     *,
     chunk: int = 512,
     spans: tuple = (),
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Multi-RHS level-scheduled walk; the batch rides the lane dimension of
     every per-wavefront launch."""

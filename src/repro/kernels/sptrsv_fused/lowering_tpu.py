@@ -22,7 +22,15 @@ level boundary (codegen pads), so every gather hits positions written by
 earlier grid steps.
 
 VMEM working set: x_perm scratch (n_pad f32) + one (K, C) cols/vals block +
-three (C,) vectors — fits for n up to ~3M rows.
+three (C,) vectors.
+
+The v5e Mosaic compiler refuses both kernels: it lowers a gather only when
+the indices have the operand's own 2-D shape, and ``jnp.take`` over the
+whole scratch is not that (``NotImplementedError: Only 2D gather is
+supported``; batched: ``ValueError: Shape mismatch in input, indices and
+output``).  A compiled ``tpu`` backend is refused at build
+(:func:`repro.kernels.backend.refuse_mosaic_gather`); the interpret
+backend runs the kernels on the host.
 """
 from __future__ import annotations
 
@@ -33,7 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 __all__ = ["fused_kernel", "fused_solve", "fused_kernel_batched",
            "fused_solve_batched"]
@@ -42,7 +49,7 @@ __all__ = ["fused_kernel", "fused_solve", "fused_kernel_batched",
 def _chunk_start(c, C):
     """Dynamic store offset in the platform's default integer dtype.
     ``program_id`` is int32; with jax_enable_x64 the other index components
-    of a multi-axis ``pl.store`` default to int64, and interpret-mode
+    of a multi-axis ref store default to int64, and interpret-mode
     ``dynamic_slice`` rejects mixed index dtypes."""
     return (c * C).astype(jnp.asarray(0).dtype)
 
@@ -68,8 +75,8 @@ def fused_kernel(bl_ref, cols_ref, vals_ref, diag_ref, out_ref, x_scr):
     xl = acc / diag_ref[...]
     # contiguous dynamic-offset store — no scatter needed
     start = _chunk_start(c, C)
-    pl.store(x_scr, (pl.dslice(start, C),), xl)
-    pl.store(out_ref, (pl.dslice(start, C),), xl)
+    x_scr[pl.ds(start, C)] = xl
+    out_ref[pl.ds(start, C)] = xl
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -80,7 +87,7 @@ def fused_solve(
     diag: jnp.ndarray,      # (n_pad,)
     *,
     chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     K, n_pad = cols.shape
     assert n_pad % chunk == 0
@@ -98,7 +105,7 @@ def fused_solve(
         out_specs=pl.BlockSpec((n_pad,), lambda c: (0,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), bl_perm.dtype),
         scratch_shapes=[pltpu.VMEM((n_pad,), bl_perm.dtype)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY,),  # sequential grid = dep order
         ),
         interpret=interpret,
@@ -129,8 +136,8 @@ def fused_kernel_batched(bl_ref, cols_ref, vals_ref, diag_ref, out_ref, x_scr):
     xl = acc / diag_ref[...][:, None]
     # contiguous dynamic-offset store along rows — no scatter needed
     start = _chunk_start(c, C)
-    pl.store(x_scr, (pl.dslice(start, C), slice(None)), xl)
-    pl.store(out_ref, (pl.dslice(start, C), slice(None)), xl)
+    x_scr[pl.ds(start, C), :] = xl
+    out_ref[pl.ds(start, C), :] = xl
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -141,7 +148,7 @@ def fused_solve_batched(
     diag: jnp.ndarray,      # (n_pad,)
     *,
     chunk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     K, n_pad = cols.shape
     m = bl_perm.shape[1]
@@ -160,7 +167,7 @@ def fused_solve_batched(
         out_specs=pl.BlockSpec((n_pad, m), lambda c: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, m), bl_perm.dtype),
         scratch_shapes=[pltpu.VMEM((n_pad, m), bl_perm.dtype)],
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY,),  # sequential grid = dep order
         ),
         interpret=interpret,

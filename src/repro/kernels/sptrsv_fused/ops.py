@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codegen import Schedule
-from repro.kernels.backend import resolve_backend
+from repro.kernels.backend import refuse_mosaic_gather, resolve_backend
 
 from . import lowering_gpu, lowering_tpu
 
@@ -119,6 +119,7 @@ def make_solver(
     chunk: int = 512,
 ) -> Callable[[jnp.ndarray], jnp.ndarray]:
     bk = resolve_backend(backend, interpret=interpret)
+    refuse_mosaic_gather(bk, "sptrsv_fused")
     low = select_lowering(bk)
     lay = build_layout(schedule, chunk)
     perm_rows = jnp.asarray(lay.perm_rows)
@@ -159,6 +160,7 @@ def make_packed_solver(
 
     Returns ``(solve(b, values), values0, repack, layout)``."""
     bk = resolve_backend(backend, interpret=interpret)
+    refuse_mosaic_gather(bk, "sptrsv_fused")
     low = select_lowering(bk)
     lay = build_layout(schedule, chunk)
     perm_rows = jnp.asarray(lay.perm_rows)

@@ -5,7 +5,7 @@ model a Triton SpTRSV actually uses (the CSR level-scheduled shape of the
 SNIPPETS.md Snippet 1 exemplar and cuSPARSE's level-scheduled solve):
 
 * ``x`` is **not** staged into on-chip memory — it stays a global-memory
-  operand and each dependency is a gather **load** (``pl.load`` with an
+  operand and each dependency is a gather **load** (ref indexing with an
   int32 index vector → per-lane pointer arithmetic in Triton), because a
   GPU has no VMEM-sized scratch to hold a whole solution vector;
 * the grid maps row blocks of the level to thread blocks (one
@@ -41,7 +41,7 @@ def level_kernel(x_ref, bl_ref, cols_ref, vals_ref, diag_ref, out_ref):
     acc = bl_ref[...]
     K = cols_ref.shape[0]
     for k in range(K):  # unrolled: K is static per level
-        acc = acc - vals_ref[k, :] * pl.load(x_ref, (cols_ref[k, :],))
+        acc = acc - vals_ref[k, :] * x_ref[cols_ref[k, :]]
     out_ref[...] = acc / diag_ref[...]
 
 
@@ -55,7 +55,7 @@ def level_kernel_batched(x_ref, bl_ref, cols_ref, vals_ref, diag_ref, out_ref):
     m = bl_ref.shape[1]
     batch_ix = jnp.arange(m, dtype=jnp.int32)[None, :]
     for k in range(K):  # unrolled: K is static per level
-        dep = pl.load(x_ref, (cols_ref[k, :][:, None], batch_ix))  # (BR, m)
+        dep = x_ref[cols_ref[k, :][:, None], batch_ix]  # (BR, m)
         acc = acc - vals_ref[k, :][:, None] * dep
     out_ref[...] = acc / diag_ref[...][:, None]
 
@@ -69,7 +69,7 @@ def level_solve_blocks(
     diag: jnp.ndarray,     # (R_pad,)
     *,
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Solve one level; returns xl (R_pad,).  Same contract as the TPU
     lowering — ops-layer packing is backend-agnostic."""
@@ -103,7 +103,7 @@ def level_solve_blocks_batched(
     diag: jnp.ndarray,     # (R_pad,)
     *,
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Solve one level for m RHS columns at once; returns xl (R_pad, m)."""
     K, R = cols.shape

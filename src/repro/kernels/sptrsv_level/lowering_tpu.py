@@ -17,10 +17,14 @@ f32.  The K loop is unrolled at trace time (K is a per-level compile-time
 constant — the "generated code" is specialized per level, exactly like the
 paper's per-level functions).
 
-TPU lowering note: ``jnp.take`` from a VMEM-resident vector lowers to the
-Mosaic dynamic-gather path (v4+).  The scatter of solved values back into x
-happens *outside* the kernel (x.at[rows].set) where XLA handles it; the
-kernel covers the bandwidth-dominant gather/FMA stream.
+TPU lowering note: the v5e Mosaic compiler refuses this kernel.  It lowers
+a gather only when the indices have the operand's own 2-D shape, and
+``jnp.take`` over the whole VMEM-resident ``x`` is not that
+(``NotImplementedError: Only 2D gather is supported``), so a compiled
+``tpu`` backend is refused at build
+(:func:`repro.kernels.backend.refuse_mosaic_gather`).  The interpret
+backend runs it on the host.  The scatter of solved values back into x
+happens *outside* the kernel (x.at[rows].set) where XLA handles it.
 """
 from __future__ import annotations
 
@@ -31,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
 
 __all__ = [
     "level_kernel",
@@ -77,7 +80,7 @@ def level_solve_blocks(
     diag: jnp.ndarray,     # (R_pad,)
     *,
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Solve one level; returns xl (R_pad,)."""
     K, R = cols.shape
@@ -96,7 +99,7 @@ def level_solve_blocks(
         ],
         out_specs=pl.BlockSpec((block_rows,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((R,), x_pad.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,),  # blocks of a level are independent
         ),
         interpret=interpret,
@@ -115,7 +118,7 @@ def level_solve_blocks_batched(
     diag: jnp.ndarray,     # (R_pad,)
     *,
     block_rows: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Solve one level for m RHS columns at once; returns xl (R_pad, m)."""
     K, R = cols.shape
@@ -134,7 +137,7 @@ def level_solve_blocks_batched(
         ],
         out_specs=pl.BlockSpec((block_rows, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, m), x_pad.dtype),
-        compiler_params=compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL,),  # blocks of a level are independent
         ),
         interpret=interpret,

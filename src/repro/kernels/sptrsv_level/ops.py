@@ -26,7 +26,7 @@ import numpy as np
 
 from repro.core.codegen import Schedule, stack_sub_slabs
 from repro.core.packed import build_packed_layout, pack_values
-from repro.kernels.backend import resolve_backend
+from repro.kernels.backend import refuse_mosaic_gather, resolve_backend
 
 from . import lowering_gpu, lowering_tpu
 
@@ -56,6 +56,7 @@ def make_solver(
     ``interpret`` is the deprecated boolean alias (see
     :func:`repro.kernels.backend.resolve_backend`)."""
     bk = resolve_backend(backend, interpret=interpret)
+    refuse_mosaic_gather(bk, "sptrsv_level")
     low = select_lowering(bk)
     n = schedule.n
     n_pad = _ceil_to(n + 1, 128)
@@ -148,6 +149,7 @@ def make_packed_solver(
 
     Returns ``(solve(b, values), values0, repack, layout)``."""
     bk = resolve_backend(backend, interpret=interpret)
+    refuse_mosaic_gather(bk, "sptrsv_level")
     low = select_lowering(bk)
 
     def _pad(r):

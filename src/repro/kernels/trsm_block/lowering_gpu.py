@@ -33,7 +33,7 @@ def block_apply(
     rhs: jnp.ndarray,   # (NB, T)
     *,
     batch_block: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     NB, T, _ = dinv.shape
     assert NB % batch_block == 0, (NB, batch_block)

@@ -36,11 +36,14 @@ def select_lowering(backend=None):
 def _dot_apply(dinv: jnp.ndarray, rhs: jnp.ndarray) -> jnp.ndarray:
     """Reference batched block apply: (B, T, T) @ (B, T[, m]) via
     ``dot_general``, accumulating in the RHS dtype (float64-exact under
-    x64 — the interpret/CPU path the differential fuzz relies on)."""
+    x64 — the interpret/CPU path the differential fuzz relies on).
+    ``HIGHEST`` keeps f32 operands out of the TPU MXU's default bf16
+    rounding."""
     r = rhs[..., None] if rhs.ndim == 2 else rhs
     out = jax.lax.dot_general(
         dinv.astype(rhs.dtype), r,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=rhs.dtype,
     )
     return out[..., 0] if rhs.ndim == 2 else out

@@ -13,6 +13,9 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS_EXTRA", "")
 )
+# A dry run compiles for 512 virtual CPU devices; on a TPU host it must not
+# take the chip, which belongs to one process at a time.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import (jax locks device count at first init).
 
 import argparse

@@ -18,12 +18,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType / axis_types only exist on newer JAX
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_mesh", "local_mesh"]
 
@@ -31,12 +26,9 @@ __all__ = ["make_production_mesh", "make_mesh", "local_mesh"]
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
     """jax.make_mesh with explicit Auto axis types (silences the v0.9
     behaviour-change warning; we use in/out_shardings + shard_map, not
-    explicit-mode sharding-in-types).  Older builds have neither AxisType
-    nor jax.make_mesh's axis_types kwarg — fall back to the plain call."""
-    if AxisType is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    explicit-mode sharding-in-types)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from .config import ModelConfig
 from .layers import dense, init_dense, init_mlp, init_rms_norm, mlp_apply, rms_norm

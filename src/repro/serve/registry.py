@@ -36,7 +36,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import CSRMatrix, SpTRSV
 from .engine import SolveEngine
 from .metrics import LatencyHistogram

@@ -2,26 +2,12 @@
 
 8 virtual CPU devices so distributed/pipeline tests can build small meshes.
 (Deliberately NOT 512 — the production-mesh device count is set only inside
-launch/dryrun.py, which owns its own process.)
-
-``jax_num_cpu_devices`` only exists on newer JAX; older builds need the
-``--xla_force_host_platform_device_count`` XLA flag set *before* the JAX
-backend initializes, so this must run at conftest import time (before any
-test module imports jax and touches devices).
+launch/dryrun.py, which owns its own process.)  ``jax_num_cpu_devices`` must
+be set before the CPU backend initializes, so this runs at conftest import
+time, before any test module touches devices.
 """
-import os
+import jax
 
 _N_DEVICES = 8
 
-try:
-    import jax
-
-    jax.config.update("jax_num_cpu_devices", _N_DEVICES)
-except AttributeError:
-    # Older JAX: force host devices via XLA_FLAGS. Safe only if the backend
-    # has not initialized yet — conftest runs before test modules import jax
-    # for real work, so append the flag and let first use pick it up.
-    flags = os.environ.get("XLA_FLAGS", "")
-    flag = f"--xla_force_host_platform_device_count={_N_DEVICES}"
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
+jax.config.update("jax_num_cpu_devices", _N_DEVICES)

@@ -54,6 +54,11 @@ def test_default_backend_mapping(monkeypatch):
         assert default_backend_name() == expected
         bk = resolve_backend(None)
         assert bk is BACKENDS[expected]
+    # an unknown platform is an error, never a silent interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+    with pytest.raises(ValueError, match="no kernel backend for JAX "
+                       "platform 'metal'"):
+        default_backend_name()
 
 
 def test_resolve_backend_specs():
@@ -120,17 +125,17 @@ def test_plan_strategy_prices_candidates_per_backend():
     d_tpu = plan_strategy(an, sched, backend="tpu")
     d_gpu = plan_strategy(an, sched, backend="gpu")
     d_cpu = plan_strategy(an, sched, backend="cpu")
-    # named hardware resolves to its compiled lowerings: fused is priced
-    assert "pallas_fused" in d_tpu.costs
+    # the GPU family resolves to its compiled lowerings: fused is priced
     assert "pallas_fused" in d_gpu.costs
-    # cpu has no compiled pallas path — fused is gated, not outscored
+    # cpu has no compiled pallas path, and Mosaic refuses the fused
+    # kernel's gather on tpu — fused is gated, not outscored
     assert "pallas_fused" not in d_cpu.costs
+    assert "pallas_fused" not in d_tpu.costs
     # both backends price the full levelset candidate set too
     for d in (d_tpu, d_gpu):
         assert {"serial", "levelset", "levelset_unroll"} <= set(d.costs)
-    # the fused dispatch shape differs: one sequential-grid launch on TPU,
-    # one launch per wavefront span on GPU — so the priced costs diverge
-    assert d_tpu.costs["pallas_fused"] != d_gpu.costs["pallas_fused"]
+    # per-backend coefficients: the same schedule prices differently
+    assert d_tpu.costs["levelset"] != d_gpu.costs["levelset"]
     assert "backend=tpu" in d_tpu.reason
     assert "backend=gpu" in d_gpu.reason
 
@@ -143,8 +148,12 @@ def test_plan_strategy_accepts_resolved_backend(monkeypatch):
     d_i = plan_strategy(an, sched, backend=resolve_backend("interpret:gpu"))
     assert "pallas_fused" not in d_i.costs
     # None resolves through jax.default_backend()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     assert "pallas_fused" in plan_strategy(an, sched).costs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    d_tpu = plan_strategy(an, sched)
+    assert "backend=tpu" in d_tpu.reason
+    assert "pallas_fused" not in d_tpu.costs
     with pytest.raises(ValueError, match="unknown planner backend"):
         plan_strategy(an, sched, backend="vulkan")
 

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import SpTRSV, analyze
 from repro.core.coarsen import blocked_candidate, build_block_schedule
 from repro.core.levels import SupernodeConfig, Supernodes, detect_supernodes

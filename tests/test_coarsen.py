@@ -17,13 +17,16 @@ Covers the PR-3 tentpole and its bugfixes:
   ids are static host-side constants (no per-level index all_gather) and
   ``collective_bytes`` skips replicated (coarsened) segments.
 """
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import SpTRSV, RewriteConfig
+from repro.core.calibrate import get_calibration
 from repro.core.codegen import build_schedule, make_levelset_solver
 from repro.core.coarsen import (
     CoarsenConfig,
@@ -225,8 +228,15 @@ def test_plan_strategy_gates_fused_on_backend_and_interpret():
     # on a TPU backend
     d_interp = plan_strategy(an, sched, backend="tpu", interpret=True)
     assert "pallas_fused" not in d_interp.costs
+    # compiled tpu: the shipped row gates fused out (Mosaic refuses its
+    # gather); a row that admits it prices it
     d_tpu = plan_strategy(an, sched, backend="tpu", interpret=False)
-    assert "pallas_fused" in d_tpu.costs
+    assert "pallas_fused" not in d_tpu.costs
+    admit = dataclasses.replace(get_calibration("tpu"),
+                                fused_max_rows=10 ** 9)
+    d_admit = plan_strategy(an, sched, backend="tpu", interpret=False,
+                            calibration=admit)
+    assert "pallas_fused" in d_admit.costs
 
 
 # -------------------------------------------------------------------------

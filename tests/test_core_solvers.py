@@ -76,7 +76,7 @@ def test_rewrite_reduces_distributed_collectives():
 
 
 def test_float64_path():
-    from repro.compat import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         L = random_lower(150, avg_offdiag=3.0, seed=9, dtype=np.float64)

@@ -34,7 +34,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import GuardConfig, RewriteConfig, SpTRSV
 from repro.sparse import PATHOLOGICAL_PATTERNS, pathological
 

@@ -85,3 +85,22 @@ def test_block_solver_matches_scipy(backend, make_L):
                                     lower=True)
     scale = max(np.abs(x_ref).max(), 1.0)
     np.testing.assert_allclose(x, x_ref, rtol=2e-4, atol=2e-4 * scale)
+
+
+@pytest.mark.parametrize("path", ["pallas_tpu", "jnp"])
+def test_block_apply_requests_full_f32_precision(path):
+    """At default precision the TPU MXU rounds f32 matmul operands to bf16
+    (a 2.9e-3 componentwise residual on a v5e band solve); both apply
+    paths of the blocked executor must ask for HIGHEST."""
+    import jax
+
+    from repro.kernels.trsm_block.ops import make_block_apply
+
+    if path == "jnp":
+        fn = make_block_apply("interpret", kernel="jnp")
+    else:
+        def fn(d, r):
+            return trsm_tpu.block_apply(d, r, batch_block=8, interpret=True)
+    text = jax.jit(fn).lower(jnp.zeros((8, 128, 128)),
+                             jnp.zeros((8, 128))).as_text()
+    assert "HIGHEST" in text

@@ -17,7 +17,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import RewriteConfig, SpTRSV
 from repro.core.csr import CSRMatrix
 from repro.sparse import lung2_like, pathological

@@ -153,7 +153,7 @@ def test_gpipe_matches_sequential():
 # compressed gradient all-reduce
 # --------------------------------------------------------------------------
 def test_compressed_allreduce_error_feedback():
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed.compress import compressed_allreduce
 
     mesh = make_mesh((8,), ("data",))

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import RewriteConfig, SpTRSV, rewrite_matrix
 from repro.sparse import banded_lower, chain_matrix, lung2_like, random_lower
 

@@ -18,7 +18,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import (
     RewriteConfig,
     RewriteReplayError,

@@ -23,7 +23,7 @@ import threading
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import CSRMatrix, SpTRSV
 from repro.serve import SolverRegistry, pattern_key
 from repro.sparse import random_lower, refresh_values

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import CSRMatrix, GuardBreakdownError, GuardConfig, SpTRSV
 from repro.serve import SolveEngine
 from repro.sparse import chain_matrix, random_lower

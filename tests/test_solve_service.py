@@ -18,7 +18,7 @@ Pins the tenancy contract on top of the registry tests:
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import CSRMatrix, GuardBreakdownError, GuardConfig
 from repro.serve import SolveService, SolverRegistry
 from repro.sparse import random_lower, refresh_values, serve_traffic

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import enable_x64
+from jax import enable_x64
 from repro.core import RewriteConfig, SpTRSV, SweepConfig
 from repro.core.csr import CSRMatrix
 from repro.core.sweep import (

@@ -101,7 +101,7 @@ def test_transpose_strategies_match_oracle_f32(spec):
 @given(matrix_spec())
 @settings(max_examples=2, deadline=None)
 def test_transpose_strategies_match_oracle_f64(spec):
-    from repro.compat import enable_x64
+    from jax import enable_x64
 
     kind, n, seed = spec
     with enable_x64():
