@@ -1,0 +1,7 @@
+"""Device: share of the traced window of solve calls in which the device ran
+no operation, in % (``bench/trace_reduce.py``)."""
+
+
+def read(ctx):
+    trace = ctx.get("trace")
+    return None if not trace else 100.0 * trace["idle_share"]
