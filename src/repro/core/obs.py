@@ -1,0 +1,38 @@
+"""Names of the profiler spans and scopes on the solve and PCG paths, and a
+process-wide counter for work that has no object to keep counts on.
+
+Spans are ``jax.profiler.TraceAnnotation(name)``: host intervals written to
+the profiler's trace, on the device ops' clock, only while a profiler runs.
+Scopes are ``jax.named_scope(name)`` inside jitted executors: they name the
+HLO ops traced within (``op_name`` metadata) and change nothing else.
+Per-solver counts live in ``SpTRSV.stats()``; this counter is for ``pcg``,
+which is a function.
+"""
+from __future__ import annotations
+
+import collections
+
+# host spans
+SOLVE = "sptrsv.solve"          # the whole of SpTRSV.solve
+PCG_SETUP = "pcg.setup"         # pcg before its loop
+PCG_ITER = "pcg.iter"           # one pcg loop iteration
+PCG_READBACK = "pcg.readback"   # one host read of a device value in pcg
+
+# device scopes
+PERMUTE = "sptrsv.permute"      # b[perm] in, x[pos] out
+SEGMENT = "sptrsv.segment"      # one segment of the level-set executor
+
+# counters
+READBACKS = "pcg.readbacks"     # one per PCG_READBACK span
+ITERATIONS = "pcg.iterations"   # pcg loop iterations entered
+
+_counts: collections.Counter = collections.Counter()
+
+
+def count(name: str, k: int = 1) -> None:
+    _counts[name] += k
+
+
+def snapshot() -> dict:
+    """Every count since the process started."""
+    return dict(_counts)

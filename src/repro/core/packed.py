@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .codegen import (
     GATHER_UNROLL_MAX_K,
     Schedule,
@@ -386,7 +387,9 @@ def make_packed_levelset_solver(
     Returns ``solve(b, values)`` with ``values = (vals_flat, diag_flat)`` as
     runtime buffers (see module docstring).  ``b`` may be ``(n,)`` or
     ``(n, m)``; the permute/un-permute happens exactly once at the
-    boundaries regardless of segment count."""
+    boundaries regardless of segment count.  Their ops carry the scope
+    ``sptrsv.permute``, each segment's ``sptrsv.segment``
+    (:mod:`repro.core.obs`)."""
     n, n_pad = layout.n, layout.n_pad
     cols_flat = jnp.asarray(layout.cols_flat)
     perm = jnp.asarray(layout.perm)
@@ -397,21 +400,24 @@ def make_packed_levelset_solver(
         dt = b.dtype
         vf = vals_flat.astype(dt)
         df = diag_flat.astype(dt)
-        bhat = b[perm]
-        if n_pad > n:
-            bhat = jnp.concatenate(
-                [bhat, jnp.zeros((n_pad - n,) + b.shape[1:], dt)])
+        with jax.named_scope(obs.PERMUTE):
+            bhat = b[perm]
+            if n_pad > n:
+                bhat = jnp.concatenate(
+                    [bhat, jnp.zeros((n_pad - n,) + b.shape[1:], dt)])
         x = jnp.zeros((n_pad,) + b.shape[1:], dt)
         for seg in layout.segments:
-            if seg.kind == "chain":
-                x = _chain_segment(x, bhat, seg, cols_flat, vf, df,
-                                   gather_unroll_max_k)
-            elif seg.R <= unroll_threshold:
-                x = _unrolled_segment(x, bhat, seg, layout, vf, df)
-            else:
-                x = _plain_segment(x, bhat, seg, cols_flat, vf, df,
-                                   gather_unroll_max_k)
-        return x[pos]
+            with jax.named_scope(obs.SEGMENT):
+                if seg.kind == "chain":
+                    x = _chain_segment(x, bhat, seg, cols_flat, vf, df,
+                                       gather_unroll_max_k)
+                elif seg.R <= unroll_threshold:
+                    x = _unrolled_segment(x, bhat, seg, layout, vf, df)
+                else:
+                    x = _plain_segment(x, bhat, seg, cols_flat, vf, df,
+                                       gather_unroll_max_k)
+        with jax.named_scope(obs.PERMUTE):
+            return x[pos]
 
     return solve
 

@@ -17,7 +17,9 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from . import obs
 from .csr import CSRMatrix
 from .rewrite import RewriteConfig
 from .solver import SpTRSV
@@ -137,6 +139,13 @@ def make_ic_preconditioner_batched(
                                   backend=backend, guard=guard)
 
 
+def _read(v) -> float:
+    """One host read of a device value: a ``pcg.readback`` span, counted."""
+    obs.count(obs.READBACKS)
+    with TraceAnnotation(obs.PCG_READBACK):
+        return float(v)
+
+
 def pcg(A: CSRMatrix, b: jnp.ndarray,
         M_inv: Optional[Callable] = None,
         *, tol: float = 1e-8, maxiter: int = 500,
@@ -149,59 +158,66 @@ def pcg(A: CSRMatrix, b: jnp.ndarray,
     consecutive iterations, the loop stops and returns the best-so-far
     iterate as non-converged instead of burning the rest of ``maxiter`` on a
     stagnated recurrence — the signature that ``k`` sweeps stopped being a
-    useful contraction at the requested ``tol``."""
+    useful contraction at the requested ``tol``.
+
+    Each host read of a device value is a ``pcg.readback`` span and one
+    ``pcg.readbacks`` count (:mod:`repro.core.obs`): two before the loop and
+    two per iteration."""
     from .codegen import build_ell, ell_spmv
 
-    ell = build_ell(A)
+    with TraceAnnotation(obs.PCG_SETUP):
+        ell = build_ell(A)
 
-    @jax.jit
-    def matvec(v):
-        return ell_spmv(ell, v)
+        @jax.jit
+        def matvec(v):
+            return ell_spmv(ell, v)
 
-    x = jnp.zeros_like(b)
-    r = b - matvec(x)
-    # Initialize the residual before the loop (maxiter=0 must return a
-    # well-formed result, not hit an unbound `res`), and guard b_norm == 0
-    # the same way pcg_batched does — otherwise b = 0 makes the tolerance
-    # test `res <= 0`, which never fires despite x = 0 being exact.
-    res = float(jnp.linalg.norm(r))
-    b_norm = float(jnp.linalg.norm(b))
-    if b_norm == 0.0:
-        b_norm = 1.0
-    if res <= tol * b_norm:
-        return PCGResult(x, 0, res, True)
-    z = M_inv(r) if M_inv else r
-    p = z
-    rz = jnp.vdot(r, z)
+        x = jnp.zeros_like(b)
+        r = b - matvec(x)
+        # Initialize the residual before the loop (maxiter=0 must return a
+        # well-formed result, not hit an unbound `res`), and guard b_norm == 0
+        # the same way pcg_batched does — otherwise b = 0 makes the tolerance
+        # test `res <= 0`, which never fires despite x = 0 being exact.
+        res = _read(jnp.linalg.norm(r))
+        b_norm = _read(jnp.linalg.norm(b))
+        if b_norm == 0.0:
+            b_norm = 1.0
+        if res <= tol * b_norm:
+            return PCGResult(x, 0, res, True)
+        z = M_inv(r) if M_inv else r
+        p = z
+        rz = jnp.vdot(r, z)
     best_res = res
     stall = 0
     for it in range(maxiter):
-        Ap = matvec(p)
-        pap = jnp.vdot(p, Ap)
-        if float(pap) == 0.0:
-            # Lanczos breakdown (p in the null space of the Krylov
-            # recurrence, e.g. A = 0 or an indefinite M).  pcg_batched
-            # guards this division; the unbatched path silently produced
-            # NaN x with converged=False unset.  Return the last finite
-            # iterate as a well-formed non-converged result.
-            return PCGResult(x, it, res, False)
-        alpha = rz / pap
-        x = x + alpha * p
-        r = r - alpha * Ap
-        res = float(jnp.linalg.norm(r))
-        if res <= tol * b_norm:
-            return PCGResult(x, it + 1, res, True)
-        if stall_window > 0:
-            if res < 0.999 * best_res:
-                best_res, stall = res, 0
-            else:
-                stall += 1
-                if stall >= stall_window:
-                    return PCGResult(x, it + 1, res, False)
-        z = M_inv(r) if M_inv else r
-        rz_new = jnp.vdot(r, z)
-        p = z + (rz_new / rz) * p
-        rz = rz_new
+        with TraceAnnotation(obs.PCG_ITER):
+            obs.count(obs.ITERATIONS)
+            Ap = matvec(p)
+            pap = jnp.vdot(p, Ap)
+            if _read(pap) == 0.0:
+                # Lanczos breakdown (p in the null space of the Krylov
+                # recurrence, e.g. A = 0 or an indefinite M).  pcg_batched
+                # guards this division; the unbatched path silently produced
+                # NaN x with converged=False unset.  Return the last finite
+                # iterate as a well-formed non-converged result.
+                return PCGResult(x, it, res, False)
+            alpha = rz / pap
+            x = x + alpha * p
+            r = r - alpha * Ap
+            res = _read(jnp.linalg.norm(r))
+            if res <= tol * b_norm:
+                return PCGResult(x, it + 1, res, True)
+            if stall_window > 0:
+                if res < 0.999 * best_res:
+                    best_res, stall = res, 0
+                else:
+                    stall += 1
+                    if stall >= stall_window:
+                        return PCGResult(x, it + 1, res, False)
+            z = M_inv(r) if M_inv else r
+            rz_new = jnp.vdot(r, z)
+            p = z + (rz_new / rz) * p
+            rz = rz_new
     return PCGResult(x, maxiter, res, False)
 
 

@@ -183,7 +183,9 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from . import obs
 from .analysis import MatrixAnalysis, analyze
 from .coarsen import (
     SEGMENT_COST,
@@ -1026,12 +1028,13 @@ class SpTRSV:
         refined, and columns that stay above tolerance are handled by the
         configured ``on_breakdown`` policy (best-effort / exact per-column
         fallback / :class:`repro.core.guard.GuardBreakdownError`)."""
-        if b.ndim not in (1, 2) or b.shape[0] != self.n:
-            raise ValueError(
-                f"b must be ({self.n},) or ({self.n}, m); got {b.shape}")
-        if self.guard is not None:
-            return self.guard.solve(b)
-        return self._solve_raw(b)
+        with TraceAnnotation(obs.SOLVE):
+            if b.ndim not in (1, 2) or b.shape[0] != self.n:
+                raise ValueError(
+                    f"b must be ({self.n},) or ({self.n}, m); got {b.shape}")
+            if self.guard is not None:
+                return self.guard.solve(b)
+            return self._solve_raw(b)
 
     def _solve_raw(self, b: jnp.ndarray) -> jnp.ndarray:
         """The unguarded solve pipeline (RHS transform + executor) against

@@ -115,3 +115,24 @@ def test_pcg_batched_maxiter_zero_and_zero_rhs():
     assert res.iters[1] > 0
     assert np.isfinite(np.asarray(res.x)).all()
     np.testing.assert_array_equal(np.asarray(res.x[:, 0]), 0.0)
+
+
+def test_pcg_counts_two_readbacks_a_solve_and_two_an_iteration():
+    """Every host read of a device value in ``pcg`` is counted: the first
+    residual norm and ``||b||``, then ``p·Ap`` and ``||r||`` per iteration."""
+    from repro.core import obs
+
+    A = poisson2d(10, 10, dtype=np.float32)
+    M = make_ic_preconditioner(ic0_factor(A), rewrite=None)
+    b = jnp.asarray(np.random.default_rng(3).normal(size=A.n)
+                    .astype(np.float32))
+    before = obs.snapshot()
+    res = pcg(A, b, M, tol=1e-6, maxiter=200)
+    after = obs.snapshot()
+
+    def delta(name):
+        return after.get(name, 0) - before.get(name, 0)
+
+    assert res.converged and res.iters > 0
+    assert delta(obs.ITERATIONS) == res.iters
+    assert delta(obs.READBACKS) == 2 * res.iters + 2
