@@ -314,12 +314,13 @@ def build_ell(M: CSRMatrix) -> EllMatrix:
     cols = np.zeros((K, M.n), dtype=np.int32)
     vals = np.zeros((K, M.n), dtype=M.dtype)
     val_src = np.full((K, M.n), -1, dtype=np.int64)
-    for i in range(M.n):
-        lo, hi = int(M.indptr[i]), int(M.indptr[i + 1])
-        k = hi - lo
-        cols[:k, i] = M.indices[lo:hi]
-        vals[:k, i] = M.data[lo:hi]
-        val_src[:k, i] = np.arange(lo, hi, dtype=np.int64)
+    # entry e of row i goes to slot e - indptr[i] of column i
+    src = np.arange(M.nnz, dtype=np.int64)
+    row = np.repeat(np.arange(M.n), row_nnz)
+    slot = src - np.repeat(M.indptr[:-1], row_nnz)
+    cols[slot, row] = M.indices
+    vals[slot, row] = M.data
+    val_src[slot, row] = src
     return EllMatrix(cols=cols, vals=vals, val_src=val_src)
 
 

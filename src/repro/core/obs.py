@@ -25,6 +25,7 @@ SEGMENT = "sptrsv.segment"      # one segment of the level-set executor
 # counters
 READBACKS = "pcg.readbacks"     # one per PCG_READBACK span
 ITERATIONS = "pcg.iterations"   # pcg loop iterations entered
+MATVEC_TRACES = "pcg.matvec_traces"  # traces of pcg's shared ELL SpMV
 
 _counts: collections.Counter = collections.Counter()
 

@@ -12,6 +12,7 @@ copy, no reverse-permutation, no second analysis pipeline.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -20,6 +21,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from . import obs
+from .codegen import _gather_sum, build_ell
 from .csr import CSRMatrix
 from .rewrite import RewriteConfig
 from .solver import SpTRSV
@@ -139,6 +141,27 @@ def make_ic_preconditioner_batched(
                                   backend=backend, guard=guard)
 
 
+@jax.jit
+def _ell_matvec(cols: jnp.ndarray, vals: jnp.ndarray,
+                v: jnp.ndarray) -> jnp.ndarray:
+    """``A v`` for ``A`` in transposed ELL form, ``v`` (n,) or (n, m).
+
+    The ELL arrays are arguments, not constants baked into the program, so
+    jit's cache keys on shapes and dtypes alone: every operator of one shape
+    shares one trace, and a solve after the first traces nothing.  Each trace
+    counts one ``pcg.matvec_traces``."""
+    obs.count(obs.MATVEC_TRACES)
+    return _gather_sum(vals, cols, v)
+
+
+def _matvec_of(A: CSRMatrix, dtype) -> Callable[[jnp.ndarray], jnp.ndarray]:
+    """``v -> A v``: ``A``'s ELL arrays put on the device once, values in the
+    solve's ``dtype``, bound to the shared :func:`_ell_matvec`."""
+    ell = build_ell(A)
+    return functools.partial(_ell_matvec, jax.device_put(ell.cols),
+                             jnp.asarray(ell.vals, dtype=dtype))
+
+
 def _read(v) -> float:
     """One host read of a device value: a ``pcg.readback`` span, counted."""
     obs.count(obs.READBACKS)
@@ -163,16 +186,9 @@ def pcg(A: CSRMatrix, b: jnp.ndarray,
     Each host read of a device value is a ``pcg.readback`` span and one
     ``pcg.readbacks`` count (:mod:`repro.core.obs`): two before the loop and
     two per iteration."""
-    from .codegen import build_ell, ell_spmv
-
     with TraceAnnotation(obs.PCG_SETUP):
-        ell = build_ell(A)
-
-        @jax.jit
-        def matvec(v):
-            return ell_spmv(ell, v)
-
         x = jnp.zeros_like(b)
+        matvec = _matvec_of(A, x.dtype)
         r = b - matvec(x)
         # Initialize the residual before the loop (maxiter=0 must return a
         # well-formed result, not hit an unbound `res`), and guard b_norm == 0
@@ -235,17 +251,10 @@ def pcg_batched(A: CSRMatrix, B: jnp.ndarray,
     converged columns freeze (masked updates) so late columns can keep
     iterating without perturbing early ones.
     """
-    from .codegen import build_ell, ell_spmv
-
     assert B.ndim == 2, f"pcg_batched expects B: (n, m); got {B.shape}"
     m = B.shape[1]
-    ell = build_ell(A)
-
-    @jax.jit
-    def matvec(V):
-        return ell_spmv(ell, V)
-
     X = jnp.zeros_like(B)
+    matvec = _matvec_of(A, X.dtype)
     R = B - matvec(X)
     Z = M_inv(R) if M_inv else R
     P = Z

@@ -136,3 +136,55 @@ def test_pcg_counts_two_readbacks_a_solve_and_two_an_iteration():
     assert res.converged and res.iters > 0
     assert delta(obs.ITERATIONS) == res.iters
     assert delta(obs.READBACKS) == 2 * res.iters + 2
+
+
+def test_pcg_traces_its_spmv_once_per_shape(monkeypatch):
+    """The ELL SpMV is one jitted function over device arrays: solves on one
+    operator, or on another of the same shape and ELL width, trace it at
+    most once between them, and a batched solve at most once more.  The
+    answers equal those of the SpMV built as a jitted closure over the host
+    ELL arrays, a new program each solve."""
+    import jax
+
+    from repro.core import obs
+    from repro.core import pcg as pcg_module
+    from repro.core.codegen import build_ell, ell_spmv
+    from repro.core.csr import CSRMatrix
+    from repro.core.pcg import pcg_batched
+
+    A = poisson2d(9, 11, dtype=np.float32)
+    A2 = CSRMatrix(A.indptr, A.indices, 1.5 * A.data, A.shape)
+    M = make_ic_preconditioner(ic0_factor(A), rewrite=None)
+    rng = np.random.default_rng(4)
+    b = jnp.asarray(rng.normal(size=A.n).astype(np.float32))
+    b2 = jnp.asarray(rng.normal(size=A.n).astype(np.float32))
+    B = jnp.asarray(rng.normal(size=(A.n, 3)).astype(np.float32))
+
+    def solves():
+        return [pcg(A, b, M, tol=1e-6), pcg(A, b2, M, tol=1e-6),
+                pcg(A2, b, None, tol=1e-6)]
+
+    def traces():
+        return obs.snapshot().get(obs.MATVEC_TRACES, 0)
+
+    start = traces()
+    got = solves()
+    after_pcg = traces()
+    got.append(pcg_batched(A, B, M, tol=1e-6))
+    assert after_pcg - start <= 1
+    assert traces() - after_pcg <= 1
+    after_all = traces()
+    solves()
+    pcg_batched(A, B, M, tol=1e-6)
+    assert traces() == after_all              # nothing traced again
+
+    def closure_matvec_of(M_, dtype):
+        ell = build_ell(M_)
+        return jax.jit(lambda v: ell_spmv(ell, v))
+
+    monkeypatch.setattr(pcg_module, "_matvec_of", closure_matvec_of)
+    want = solves() + [pcg_batched(A, B, M, tol=1e-6)]
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.iters, w.iters)
+        np.testing.assert_allclose(np.asarray(g.x), np.asarray(w.x),
+                                   rtol=1e-6, atol=1e-6)

@@ -71,6 +71,19 @@ def test_coarsened_levelset_executor_compiles_for_v5e(one_chip, m):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("m", [1, 8])
+def test_pcg_ell_matvec_compiles_for_v5e(one_chip, m):
+    """PCG's SpMV, its ELL arrays arguments of the program, at the 512x512
+    5-point Laplacian's size (n = 262,144, K = 5)."""
+    from repro.core.pcg import _ell_matvec
+
+    n, K = 512 * 512, 5
+    v = _sds(one_chip, (n,) if m == 1 else (n, m))
+    compiled = _ell_matvec.lower(_sds(one_chip, (K, n), jnp.int32),
+                                 _sds(one_chip, (K, n)), v).compile()
+    assert compiled.memory_analysis() is not None
+
+
 _K, _N_PAD, _CHUNK = 5, 4096, 512
 
 
