@@ -340,13 +340,16 @@ def build_offdiag_ell(M: CSRMatrix, *, upper: bool = False):
     cols = np.zeros((K, M.n), dtype=np.int32)
     vals = np.zeros((K, M.n), dtype=M.dtype)
     val_src = np.full((K, M.n), -1, dtype=np.int64)
-    for i in range(M.n):
-        lo, hi = int(M.indptr[i]), int(M.indptr[i + 1])
-        sl = slice(lo + 1, hi) if upper else slice(lo, hi - 1)
-        k = sl.stop - sl.start
-        cols[:k, i] = M.indices[sl]
-        vals[:k, i] = M.data[sl]
-        val_src[:k, i] = np.arange(sl.start, sl.stop, dtype=np.int64)
+    # entry e of row i goes to slot e - indptr[i] (- 1 past a leading
+    # diagonal) of column i; the diagonal entries are left out
+    src = np.arange(M.nnz, dtype=np.int64)
+    row = np.repeat(np.arange(M.n), M.row_nnz())
+    start = M.indptr[:-1][row]
+    slot = src - start - (1 if upper else 0)
+    off = src != (start if upper else M.indptr[1:][row] - 1)
+    cols[slot[off], row[off]] = M.indices[off]
+    vals[slot[off], row[off]] = M.data[off]
+    val_src[slot[off], row[off]] = src[off]
     diag = M.diagonal(first=upper)
     diag_src = (M.indptr[:-1] if upper else M.indptr[1:] - 1).astype(np.int64)
     return EllMatrix(cols=cols, vals=vals, val_src=val_src), diag, diag_src

@@ -7,6 +7,8 @@ from .generate import (
     random_lower,
     refresh_values,
     serve_traffic,
+    stencil27,
+    hpcg_hierarchy,
 )
 from .faults import (
     FAULT_KINDS,
@@ -31,6 +33,8 @@ __all__ = [
     "random_lower",
     "refresh_values",
     "serve_traffic",
+    "stencil27",
+    "hpcg_hierarchy",
     "PATHOLOGICAL_PATTERNS",
     "diag_condition",
     "pathological",

@@ -21,6 +21,8 @@ __all__ = [
     "lung2_like",
     "poisson2d",
     "ic0_factor",
+    "stencil27",
+    "hpcg_hierarchy",
     "refresh_values",
     "serve_traffic",
 ]
@@ -275,3 +277,66 @@ def ic0_factor(A: CSRMatrix, shift: float = 0.05) -> CSRMatrix:
         for j in sorted(Lrows[i]):
             rows.append(i); cols.append(j); vals.append(Lrows[i][j])
     return _finalize(rows, cols, vals, n, A.dtype)
+
+
+def stencil27(nx: int, ny: int, nz: int, dtype=np.float64) -> CSRMatrix:
+    """HPCG's ``GenerateProblem`` operator on an ``nx * ny * nz`` grid: 26 on
+    the diagonal and -1 for each of the up to 26 neighbours inside the grid
+    (no halo; boundary rows have fewer).  Row ``i = x + nx * (y + ny * z)``;
+    columns ascend within each row.  Symmetric positive definite."""
+    if min(nx, ny, nz) < 1:
+        raise ValueError(f"grid dimensions must be positive; got "
+                         f"{(nx, ny, nz)}")
+    n = nx * ny * nz
+    i = np.arange(n, dtype=np.int64)
+    x, y, z = i % nx, (i // nx) % ny, i // (nx * ny)
+    cols = np.empty((n, 27), dtype=np.int64)
+    inside = np.empty((n, 27), dtype=bool)
+    k = 0
+    # offsets in (dz, dy, dx) order give ascending columns within a row
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                inside[:, k] = ((x + dx >= 0) & (x + dx < nx) & (y + dy >= 0)
+                                & (y + dy < ny) & (z + dz >= 0)
+                                & (z + dz < nz))
+                cols[:, k] = i + dx + nx * (dy + ny * dz)
+                k += 1
+    indptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(inside.sum(axis=1), out=indptr[1:])
+    indices = cols[inside]
+    rows = np.repeat(i, np.diff(indptr))
+    data = np.where(indices == rows, 26.0, -1.0).astype(dtype)
+    return CSRMatrix(indptr, indices, data, (n, n))
+
+
+def hpcg_hierarchy(nx: int, ny: int, nz: int, levels: int = 4,
+                   dtype=np.float64):
+    """HPCG's multigrid hierarchy (``GenerateCoarseProblem``): ``levels``
+    grids, each coarser one halving every dimension, with the
+    :func:`stencil27` operator re-applied on each.
+
+    Returns ``(As, f2c)``: ``As[l]`` the operator of level ``l`` (0 the
+    finest), ``f2c[l]`` the fine row of level ``l`` that each row of level
+    ``l + 1`` injects from, coarse point ``(i, j, k)`` at fine point
+    ``(2i, 2j, 2k)``.  Every dimension must be divisible by
+    ``2 ** (levels - 1)``."""
+    if levels < 1:
+        raise ValueError(f"levels must be at least 1; got {levels}")
+    step = 2 ** (levels - 1)
+    if min(nx, ny, nz) < step or any(d % step for d in (nx, ny, nz)):
+        raise ValueError(
+            f"grid {(nx, ny, nz)} cannot be halved {levels - 1} times: each "
+            f"dimension must be a positive multiple of {step}")
+    As, f2c = [], []
+    dims = (nx, ny, nz)
+    for lv in range(levels):
+        fx, fy, fz = dims
+        As.append(stencil27(fx, fy, fz, dtype))
+        if lv + 1 < levels:
+            cx, cy, cz = fx // 2, fy // 2, fz // 2
+            c = np.arange(cx * cy * cz, dtype=np.int64)
+            xc, yc, zc = c % cx, (c // cx) % cy, c // (cx * cy)
+            f2c.append(2 * xc + fx * (2 * yc + fy * 2 * zc))
+            dims = (cx, cy, cz)
+    return As, f2c

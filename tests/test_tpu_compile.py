@@ -84,6 +84,33 @@ def test_pcg_ell_matvec_compiles_for_v5e(one_chip, m):
     assert compiled.memory_analysis() is not None
 
 
+@pytest.mark.parametrize("edge", [104, 52])
+def test_mg_programs_compile_for_v5e(one_chip, edge):
+    """The multigrid V-cycle's own programs (``repro.core.mg``: SYMGS's
+    SpMV and updates, the restriction and the prolongation), their arrays
+    arguments, at HPCG's 104^3 and 52^3 levels: ``U`` holds up to 13
+    entries a row, the rows of ``A`` at ``f2c`` up to 27."""
+    from repro.core import mg
+
+    n, nc = edge ** 3, (edge // 2) ** 3
+
+    def f32(*shape):
+        return _sds(one_chip, shape)
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    lowered = [
+        mg._diag_times.lower(f32(n), f32(n)),
+        mg._upper_rhs.lower(i32(13, n), f32(13, n), f32(n), f32(n)),
+        mg._backward_rhs.lower(f32(n), f32(n), f32(n)),
+        mg._restrict.lower(i32(27, nc), f32(27, nc), i32(nc), f32(n), f32(n)),
+        mg._prolong.lower(i32(nc), f32(n), f32(nc)),
+    ]
+    for program in lowered:
+        assert program.compile().memory_analysis() is not None
+
+
 _K, _N_PAD, _CHUNK = 5, 4096, 512
 
 
