@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .csr import CSRMatrix
 from .levels import LevelSets, build_level_sets, compute_upper_levels
 from .rewrite import RewriteResult
@@ -35,10 +36,12 @@ __all__ = [
     "LevelSlab",
     "Schedule",
     "EllMatrix",
+    "Diagonals",
     "GATHER_UNROLL_MAX_K",
     "build_schedule",
     "build_ell",
     "build_offdiag_ell",
+    "build_spmv",
     "slab_padded_flops",
     "stack_sub_slabs",
     "serial_arrays",
@@ -47,6 +50,7 @@ __all__ = [
     "make_blocked_solver",
     "make_rhs_transform",
     "ell_spmv",
+    "spmv",
 ]
 
 logger = logging.getLogger(__name__)
@@ -355,6 +359,49 @@ def build_offdiag_ell(M: CSRMatrix, *, upper: bool = False):
     return EllMatrix(cols=cols, vals=vals, val_src=val_src), diag, diag_src
 
 
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class Diagonals:
+    """The pattern of a matrix stored by diagonals (DIA): the ascending
+    ``col − row`` offsets of its stored diagonals.  A static pytree, so a
+    jitted :func:`spmv` keys its cache on the offsets and takes only the
+    values as an argument."""
+
+    offsets: tuple
+
+
+def build_spmv(M: CSRMatrix) -> tuple:
+    """``M`` as ``(pattern, vals)`` for :func:`spmv`, in whichever of two
+    formats stores fewer bytes.
+
+    DIA — pattern a :class:`Diagonals`, ``vals`` ``(ndiag, rows)``, zero
+    where a row lacks a diagonal — when ``ndiag · b ≤ K · (b + 4)`` for
+    ``b``-byte values, ``K`` the ELL width and 4 the bytes of ELL's int32
+    column: a stencil's few offsets.  Otherwise ELL as :func:`build_ell`
+    packs it, pattern the ``(K, rows)`` columns.  Offsets ascend, as each
+    row's columns do, so both formats visit a row's terms in one order.
+    Each build counts its stored entries as ``spmv.dia_entries`` or
+    ``spmv.ell_entries`` (:mod:`repro.core.obs`)."""
+    rows, cols = M.shape
+    row_nnz = M.row_nnz()
+    K = max(int(row_nnz.max(initial=0)), 1)
+    row = np.repeat(np.arange(rows, dtype=np.int64), row_nnz)
+    # col - row, shifted by rows - 1 to count from 0
+    off = M.indices - row + (rows - 1)
+    present = np.flatnonzero(np.bincount(off, minlength=rows + cols - 1))
+    b = M.dtype.itemsize
+    if present.size * b <= K * (b + 4):
+        slot = np.zeros(rows + cols - 1, dtype=np.int64)
+        slot[present] = np.arange(present.size)
+        vals = np.zeros((present.size, rows), dtype=M.dtype)
+        vals[slot[off], row] = M.data
+        obs.count(obs.SPMV_DIA_ENTRIES, vals.size)
+        return Diagonals(tuple((present - (rows - 1)).tolist())), vals
+    ell = build_ell(M)
+    obs.count(obs.SPMV_ELL_ENTRIES, ell.vals.size)
+    return ell.cols, ell.vals
+
+
 # --------------------------------------------------------------------------
 # Executors (pure JAX)
 #
@@ -400,6 +447,33 @@ def _gather_sum(
     for k in range(1, cols.shape[0]):
         acc = acc + vals[k][:, None] * x[cols[k]]
     return acc
+
+
+def _diagonal_sum(vals: jnp.ndarray, offsets: tuple,
+                  x: jnp.ndarray) -> jnp.ndarray:
+    """``y[i] = sum_d vals[d, i] * x[i + offsets[d]]``: each term a static
+    slice of ``x`` zero-padded along axis 0, so no index array and no
+    gather; ``x`` ``(cols,)`` or ``(cols, m)``."""
+    rows = vals.shape[1]
+    if not offsets:
+        return jnp.zeros((rows,) + x.shape[1:], x.dtype)
+    lo = max(0, -offsets[0])
+    hi = max(0, offsets[-1] + rows - x.shape[0])
+    xp = jnp.pad(x, [(lo, hi)] + [(0, 0)] * (x.ndim - 1))
+    acc = None
+    for d, off in enumerate(offsets):
+        term = _coef(vals[d], x) * xp[lo + off:lo + off + rows]
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def spmv(pattern, vals: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``y = M x`` for ``M`` as :func:`build_spmv` stores it: shifted slices
+    for DIA, a gather for ELL.  ``x`` may be ``(cols,)`` or batched
+    ``(cols, m)``."""
+    if isinstance(pattern, Diagonals):
+        return _diagonal_sum(vals, pattern.offsets, x)
+    return _gather_sum(vals, pattern, x)
 
 
 def ell_spmv(ell: EllMatrix, v: jnp.ndarray) -> jnp.ndarray:

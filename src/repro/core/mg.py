@@ -42,18 +42,20 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from . import obs
-from .codegen import build_ell, build_offdiag_ell
+from .codegen import build_ell, build_spmv
 from .csr import CSRMatrix
-from .pcg import _ell_matvec
+from .pcg import _matvec
 from .solver import SpTRSV
 
 __all__ = ["MGLevel", "MGPreconditioner", "make_mg_preconditioner"]
 
 
-def _triangle(A: CSRMatrix, *, upper: bool) -> CSRMatrix:
-    """``triu(A)`` or ``tril(A)``, diagonal included."""
+def _triangle(A: CSRMatrix, *, upper: bool,
+              strict: bool = False) -> CSRMatrix:
+    """``triu(A)`` or ``tril(A)``, diagonal included unless ``strict``."""
     rows = np.repeat(np.arange(A.n, dtype=np.int64), A.row_nnz())
-    keep = A.indices >= rows if upper else A.indices <= rows
+    side = A.indices - rows if upper else rows - A.indices
+    keep = side > 0 if strict else side >= 0
     indptr = np.zeros(A.n + 1, dtype=np.int64)
     np.cumsum(np.bincount(rows[keep], minlength=A.n), out=indptr[1:])
     return CSRMatrix(indptr, A.indices[keep], A.data[keep], A.shape)
@@ -82,10 +84,10 @@ def _diag_times(d, x1):
 
 
 @jax.jit
-def _upper_rhs(cols, vals, x0, r):
+def _upper_rhs(pattern, vals, x0, r):
     """``c = U x⁰`` and the forward right-hand side ``r − c``."""
     with jax.named_scope(obs.MG_SMOOTH):
-        c = _ell_matvec(cols, vals.astype(x0.dtype), x0)
+        c = _matvec(pattern, vals.astype(x0.dtype), x0)
         return c, r - c
 
 
@@ -100,7 +102,7 @@ def _backward_rhs(c, d, x1):
 def _restrict(cols, vals, f2c, r, x):
     """``(r − A x)[f2c]`` from the rows of ``A`` at ``f2c``."""
     with jax.named_scope(obs.MG_TRANSFER):
-        return r[f2c] - _ell_matvec(cols, vals.astype(x.dtype), x)
+        return r[f2c] - _matvec(cols, vals.astype(x.dtype), x)
 
 
 @jax.jit
@@ -121,9 +123,10 @@ class MGLevel:
     """One level's operators, resident on the device.
 
     ``fwd`` solves ``(D + L) x = b``, ``bwd`` ``(D + U) x = b``; ``diag`` is
-    ``D``; ``upper`` is ``U`` as transposed ELL ``(cols, vals)``; ``f2c``
-    the fine rows the next level injects from and ``rows_at_f2c`` those
-    rows of ``A`` as transposed ELL, both None on the coarsest level."""
+    ``D``; ``upper`` is ``U`` as ``build_spmv`` stores it, ``(pattern,
+    vals)``: by diagonals for a stencil; ``f2c`` the fine rows the next
+    level injects from and ``rows_at_f2c`` those rows of ``A`` as
+    transposed ELL ``(cols, vals)``, both None on the coarsest level."""
 
     fwd: SpTRSV
     bwd: SpTRSV
@@ -181,8 +184,8 @@ def make_mg_preconditioner(As: Sequence[CSRMatrix],
     injects from), as :func:`repro.sparse.hpcg_hierarchy` returns them.
 
     Each level builds ``SpTRSV.build_pair(tril(A), strategy=strategy,
-    rewrite=None)`` and puts ``D``, ``U`` and the rows of ``A`` at ``f2c``
-    on the device once."""
+    rewrite=None)`` and puts ``D``, ``U`` (in the format ``build_spmv``
+    picks) and the rows of ``A`` at ``f2c`` on the device once."""
     if len(f2c) != len(As) - 1:
         raise ValueError(f"need one f2c map between each pair of levels: "
                          f"{len(As)} levels, {len(f2c)} maps")
@@ -191,10 +194,9 @@ def make_mg_preconditioner(As: Sequence[CSRMatrix],
         if A.shape[0] != A.shape[1]:
             raise ValueError(f"level {depth} operator is not square: "
                              f"{A.shape}")
-        fwd, bwd = SpTRSV.build_pair(_triangle(A, upper=False),
-                                     strategy=strategy, rewrite=None)
-        upper, diag, _ = build_offdiag_ell(_triangle(A, upper=True),
-                                           upper=True)
+        lower = _triangle(A, upper=False)
+        fwd, bwd = SpTRSV.build_pair(lower, strategy=strategy, rewrite=None)
+        upper = build_spmv(_triangle(A, upper=True, strict=True))
         inject = rows = None
         if depth < len(f2c):
             idx = np.asarray(f2c[depth], dtype=np.int64)
@@ -205,7 +207,8 @@ def make_mg_preconditioner(As: Sequence[CSRMatrix],
                     f"level {depth} (0 to {A.n - 1})")
             inject = jax.device_put(idx.astype(np.int32))
             rows = _on_device(build_ell(_rows(A, idx)))
-        levels.append(MGLevel(fwd=fwd, bwd=bwd, diag=jax.device_put(diag),
-                              upper=_on_device(upper), f2c=inject,
+        levels.append(MGLevel(fwd=fwd, bwd=bwd,
+                              diag=jax.device_put(lower.diagonal()),
+                              upper=jax.device_put(upper), f2c=inject,
                               rows_at_f2c=rows))
     return MGPreconditioner(levels)

@@ -7,7 +7,8 @@ the profiler's trace, on the device ops' clock, only while a profiler runs.
 Scopes are ``jax.named_scope(name)`` inside jitted executors: they name the
 HLO ops traced within (``op_name`` metadata) and change nothing else.
 Per-solver counts live in ``SpTRSV.stats()``; this counter is for ``pcg``,
-and for the multigrid V-cycle, which runs many solvers.
+for the multigrid V-cycle, which runs many solvers, and for the SpMV
+operators they build.
 """
 from __future__ import annotations
 
@@ -29,9 +30,11 @@ MG_TRANSFER = "mg.transfer"     # restriction residual, injection, prolongation
 # counters
 READBACKS = "pcg.readbacks"     # one per PCG_READBACK span
 ITERATIONS = "pcg.iterations"   # pcg loop iterations entered
-MATVEC_TRACES = "pcg.matvec_traces"  # traces of pcg's shared ELL SpMV
+MATVEC_TRACES = "pcg.matvec_traces"  # traces of pcg's shared SpMV
 MG_VCYCLES = "mg.vcycles"       # one per MG_VCYCLE span
 MG_DISPATCHES = "mg.dispatches"  # jitted programs the V-cycles enqueued
+SPMV_DIA_ENTRIES = "spmv.dia_entries"  # entries of SpMV operators built as DIA
+SPMV_ELL_ENTRIES = "spmv.ell_entries"  # entries of those built as ELL
 
 _counts: collections.Counter = collections.Counter()
 

@@ -21,7 +21,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from . import obs
-from .codegen import _gather_sum, build_ell
+from .codegen import build_spmv, spmv
 from .csr import CSRMatrix
 from .rewrite import RewriteConfig
 from .solver import SpTRSV
@@ -142,24 +142,26 @@ def make_ic_preconditioner_batched(
 
 
 @jax.jit
-def _ell_matvec(cols: jnp.ndarray, vals: jnp.ndarray,
-                v: jnp.ndarray) -> jnp.ndarray:
-    """``A v`` for ``A`` in transposed ELL form, ``v`` (n,) or (n, m).
+def _matvec(pattern, vals: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """``A v`` for ``A`` as :func:`~repro.core.codegen.build_spmv` stores
+    it (DIA or ELL), ``v`` (n,) or (n, m).
 
-    The ELL arrays are arguments, not constants baked into the program, so
-    jit's cache keys on shapes and dtypes alone: every operator of one shape
-    shares one trace, and a solve after the first traces nothing.  Each trace
-    counts one ``pcg.matvec_traces``."""
+    The values, and ELL's columns, are arguments, not constants baked into
+    the program, so jit's cache keys on shapes, dtypes and DIA's offsets
+    alone: every operator of one shape and pattern shares one trace, and a
+    solve after the first traces nothing.  Each trace counts one
+    ``pcg.matvec_traces``."""
     obs.count(obs.MATVEC_TRACES)
-    return _gather_sum(vals, cols, v)
+    return spmv(pattern, vals, v)
 
 
 def _matvec_of(A: CSRMatrix, dtype) -> Callable[[jnp.ndarray], jnp.ndarray]:
-    """``v -> A v``: ``A``'s ELL arrays put on the device once, values in the
-    solve's ``dtype``, bound to the shared :func:`_ell_matvec`."""
-    ell = build_ell(A)
-    return functools.partial(_ell_matvec, jax.device_put(ell.cols),
-                             jnp.asarray(ell.vals, dtype=dtype))
+    """``v -> A v``: ``A`` in the format :func:`build_spmv` picks, put on
+    the device once, values in the solve's ``dtype``, bound to the shared
+    :func:`_matvec`."""
+    pattern, vals = build_spmv(A)
+    return functools.partial(_matvec, jax.device_put(pattern),
+                             jnp.asarray(vals, dtype=dtype))
 
 
 def _read(v) -> float:

@@ -1,6 +1,7 @@
 """PCG + IC(0)/SpTRSV preconditioner integration."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.pcg import make_ic_preconditioner, pcg
 from repro.core.rewrite import RewriteConfig
@@ -138,21 +139,38 @@ def test_pcg_counts_two_readbacks_a_solve_and_two_an_iteration():
     assert delta(obs.READBACKS) == 2 * res.iters + 2
 
 
-def test_pcg_traces_its_spmv_once_per_shape(monkeypatch):
-    """The ELL SpMV is one jitted function over device arrays: solves on one
-    operator, or on another of the same shape and ELL width, trace it at
-    most once between them, and a batched solve at most once more.  The
-    answers equal those of the SpMV built as a jitted closure over the host
-    ELL arrays, a new program each solve."""
+def _permuted(A):
+    """``P A Pᵀ`` for a seeded random permutation ``P``: the same SPD
+    M-matrix with its columns scattered off a few diagonals."""
+    from repro.core.csr import from_coo
+
+    perm = np.random.default_rng(9).permutation(A.n)
+    inv = np.argsort(perm)
+    rows = np.repeat(np.arange(A.n), A.row_nnz())
+    return from_coo(inv[rows], inv[A.indices], A.data, A.shape)
+
+
+@pytest.mark.parametrize("form", ["dia", "ell"])
+def test_pcg_traces_its_spmv_once_per_shape(monkeypatch, form):
+    """The SpMV is one jitted function over device arrays: solves on one
+    operator, or on another of the same shape and pattern, trace it at
+    most once between them, and a batched solve at most once more.  This
+    holds for a stencil, which ``build_spmv`` stores by diagonals, and for a
+    scattered pattern, which it stores as ELL.  The answers equal those of
+    the SpMV built as a jitted closure over the host ELL arrays, a new
+    program each solve."""
     import jax
 
     from repro.core import obs
     from repro.core import pcg as pcg_module
-    from repro.core.codegen import build_ell, ell_spmv
+    from repro.core.codegen import Diagonals, build_ell, build_spmv, ell_spmv
     from repro.core.csr import CSRMatrix
     from repro.core.pcg import pcg_batched
 
     A = poisson2d(9, 11, dtype=np.float32)
+    if form == "ell":
+        A = _permuted(A)
+    assert isinstance(build_spmv(A)[0], Diagonals) == (form == "dia")
     A2 = CSRMatrix(A.indptr, A.indices, 1.5 * A.data, A.shape)
     M = make_ic_preconditioner(ic0_factor(A), rewrite=None)
     rng = np.random.default_rng(4)

@@ -75,24 +75,53 @@ def test_coarsened_levelset_executor_compiles_for_v5e(one_chip, m):
 def test_pcg_ell_matvec_compiles_for_v5e(one_chip, m):
     """PCG's SpMV, its ELL arrays arguments of the program, at the 512x512
     5-point Laplacian's size (n = 262,144, K = 5)."""
-    from repro.core.pcg import _ell_matvec
+    from repro.core.pcg import _matvec
 
     n, K = 512 * 512, 5
     v = _sds(one_chip, (n,) if m == 1 else (n, m))
-    compiled = _ell_matvec.lower(_sds(one_chip, (K, n), jnp.int32),
-                                 _sds(one_chip, (K, n)), v).compile()
+    compiled = _matvec.lower(_sds(one_chip, (K, n), jnp.int32),
+                             _sds(one_chip, (K, n)), v).compile()
     assert compiled.memory_analysis() is not None
+
+
+def _offsets_27(edge):
+    """The ``col − row`` offsets of the 27-point stencil on an ``edge^3``
+    grid, ascending."""
+    r = (-1, 0, 1)
+    return tuple(sorted(x + edge * (y + edge * z)
+                        for x in r for y in r for z in r))
+
+
+@pytest.mark.parametrize("m", [1, 8])
+@pytest.mark.parametrize("n, offsets", [
+    (512 ** 2, (-512, -1, 0, 1, 512)), (104 ** 3, _offsets_27(104))],
+    ids=["p512", "s104"])
+def test_pcg_dia_matvec_compiles_for_v5e(one_chip, n, offsets, m):
+    """PCG's SpMV by diagonals, its values an argument and its offsets
+    static, at the 512x512 5-point Laplacian (5 offsets) and HPCG's 104^3
+    27-point stencil (27 offsets)."""
+    from repro.core.codegen import Diagonals
+    from repro.core.pcg import _matvec
+
+    v = _sds(one_chip, (n,) if m == 1 else (n, m))
+    compiled = _matvec.lower(Diagonals(offsets),
+                             _sds(one_chip, (len(offsets), n)), v).compile()
+    assert compiled.memory_analysis() is not None
+    assert "gather" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("edge", [104, 52])
 def test_mg_programs_compile_for_v5e(one_chip, edge):
     """The multigrid V-cycle's own programs (``repro.core.mg``: SYMGS's
     SpMV and updates, the restriction and the prolongation), their arrays
-    arguments, at HPCG's 104^3 and 52^3 levels: ``U`` holds up to 13
-    entries a row, the rows of ``A`` at ``f2c`` up to 27."""
+    arguments, at HPCG's 104^3 and 52^3 levels: ``U`` by its 13 diagonals,
+    the rows of ``A`` at ``f2c`` as ELL of up to 27 entries a row."""
     from repro.core import mg
+    from repro.core.codegen import Diagonals
 
     n, nc = edge ** 3, (edge // 2) ** 3
+    upper = Diagonals(tuple(o for o in _offsets_27(edge) if o > 0))
+    assert len(upper.offsets) == 13
 
     def f32(*shape):
         return _sds(one_chip, shape)
@@ -102,7 +131,7 @@ def test_mg_programs_compile_for_v5e(one_chip, edge):
 
     lowered = [
         mg._diag_times.lower(f32(n), f32(n)),
-        mg._upper_rhs.lower(i32(13, n), f32(13, n), f32(n), f32(n)),
+        mg._upper_rhs.lower(upper, f32(13, n), f32(n), f32(n)),
         mg._backward_rhs.lower(f32(n), f32(n), f32(n)),
         mg._restrict.lower(i32(27, nc), f32(27, nc), i32(nc), f32(n), f32(n)),
         mg._prolong.lower(i32(nc), f32(n), f32(nc)),
